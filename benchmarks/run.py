@@ -26,7 +26,9 @@ def main() -> None:
         bench_table_s1,
         common,
     )
+    from repro.kernels import backend
 
+    backend.enable_compile_cache()
     out_dir = sys.argv[1] if len(sys.argv) > 1 else "."
     print("name,us_per_call,derived")
     for mod in (

@@ -58,7 +58,6 @@ from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.bayesnet.noise import NoiseModel, perturbed_cdf_rows
@@ -618,9 +617,9 @@ def compile_network(
                     total_frames=b, decide=decide, **sweep_kwargs,
                 )
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(P(), bspec),
-                out_specs=(bspec,) * (3 if decide else 2), check_rep=False,
+                out_specs=(bspec,) * (3 if decide else 2), check_vma=False,
             )(rng.seed_words(key), ev_frames)
 
         @jax.jit

@@ -42,7 +42,9 @@ RAND_WORDS_PER_OUT_WORD = 8
 def threshold_from_p(p: jnp.ndarray) -> jnp.ndarray:
     """Probability -> 8-bit comparator threshold in [0, 256] (uint32)."""
     p = jnp.asarray(p, jnp.float32)
-    return jnp.clip(jnp.round(p * 256.0), 0.0, 256.0).astype(jnp.uint32)
+    # through int32: Mosaic has no float32 -> uint32 cast, and [0, 256] fits
+    t = jnp.clip(jnp.round(p * 256.0), 0.0, 256.0).astype(jnp.int32)
+    return t.astype(jnp.uint32)
 
 
 def threshold_int(p: float) -> int:

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.rng import threshold_from_p
+from repro.kernels.backend import first_argmax
 
 
 def _decide_kernel(p_ref, rand_ref, dec_ref, cnt_ref):
@@ -39,11 +40,7 @@ def _decide_kernel(p_ref, rand_ref, dec_ref, cnt_ref):
         total = total + jnp.sum(joint.astype(jnp.int32), axis=-1)
     cnt_ref[...] = total
     # first-occurrence argmax via iota+min (lowers on Mosaic, unlike argmax)
-    best = jnp.max(total, axis=-1, keepdims=True)
-    idx = jax.lax.broadcasted_iota(jnp.int32, total.shape, 1)
-    dec_ref[...] = jnp.min(
-        jnp.where(total == best, idx, jnp.int32(total.shape[-1])), axis=-1
-    )
+    dec_ref[...] = first_argmax(total)[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -63,7 +60,9 @@ def bayes_decide_pallas(
     block_r = min(block_r, r)
     assert r % block_r == 0, f"rows {r} not divisible by block {block_r}"
     grid = (r // block_r,)
-    return pl.pallas_call(
+    # decisions leave as an (R, 1) column: Mosaic tiles a rank-1 block
+    # differently from XLA's layout of the (R,) array
+    dec, counts = pl.pallas_call(
         _decide_kernel,
         grid=grid,
         in_specs=[
@@ -71,12 +70,13 @@ def bayes_decide_pallas(
             pl.BlockSpec((m, block_r, k, n_rand), lambda i: (0, i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_r,), lambda i: (i,)),
+            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_r, k), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((r,), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1), jnp.int32),
             jax.ShapeDtypeStruct((r, k), jnp.int32),
         ],
         interpret=interpret,
     )(p, rand_words)
+    return dec[:, 0], counts

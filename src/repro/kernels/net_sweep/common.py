@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitops, rng
+from repro.kernels import backend
 
 # np scalar (not a committed jax array): Pallas kernels cannot close over
 # device constants, and np scalars fold into jaxpr literals.
@@ -375,7 +376,7 @@ def decide_counts(plan: SweepPlan, numer: jnp.ndarray, denom: jnp.ndarray):
         slots = numer[..., off : off + q_card - 1]
         c0 = denom - jnp.sum(slots, axis=-1)
         counts = jnp.concatenate([c0[..., None], slots], axis=-1)
-        decs.append(jnp.argmax(counts, axis=-1).astype(jnp.int32))
+        decs.append(backend.first_argmax(counts))
     return jnp.stack(decs, axis=-1)
 
 

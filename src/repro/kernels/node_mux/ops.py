@@ -68,7 +68,9 @@ def node_mux(
     if mode not in ("gather", "rows"):
         raise ValueError(f"unknown node_mux mode {mode!r}")
     interpret = backend.resolve_interpret(interpret)
-    use_kernel = backend.resolve_use_kernel(use_kernel, interpret)
+    # the TPU compiler (Mosaic) refuses this kernel (float32 -> uint32 casts,
+    # uint32 reductions): the bit-exact reference is the default everywhere
+    use_kernel = bool(use_kernel)
     cpt = jnp.asarray(cpt, jnp.float32)
     m = parents.shape[0]
     l = cpt.shape[-1]
@@ -127,7 +129,9 @@ def node_mux_categorical(
     """
     assert n_bits % 32 == 0, "kernel path consumes whole uint32 entropy words"
     interpret = backend.resolve_interpret(interpret)
-    use_kernel = backend.resolve_use_kernel(use_kernel, interpret)
+    # the TPU compiler (Mosaic) refuses this kernel (float32 -> uint32 casts,
+    # uint32 reductions): the bit-exact reference is the default everywhere
+    use_kernel = bool(use_kernel)
     k = int(cards[0])
     pcards = tuple(int(c) for c in cards[1:])
     l = 1

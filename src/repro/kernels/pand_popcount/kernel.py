@@ -25,7 +25,7 @@ def _pand_kernel(streams_ref, out_ref):
     x = (x & jnp.uint32(0x33333333)) + ((x >> 2) & jnp.uint32(0x33333333))
     x = (x + (x >> 4)) & jnp.uint32(0x0F0F0F0F)
     counts = (x * jnp.uint32(0x01010101)) >> 24
-    out_ref[...] = jnp.sum(counts.astype(jnp.int32), axis=-1)
+    out_ref[...] = jnp.sum(counts.astype(jnp.int32), axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -37,11 +37,13 @@ def pand_popcount_pallas(
     block_r = min(block_r, r)
     assert r % block_r == 0, f"rows {r} not divisible by block {block_r}"
     grid = (r // block_r,)
+    # counts leave as an (R, 1) column: Mosaic tiles a rank-1 block
+    # differently from XLA's layout of the (R,) array
     return pl.pallas_call(
         _pand_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((m, block_r, n_words), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((block_r,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((r,), jnp.int32),
+        out_specs=pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1), jnp.int32),
         interpret=interpret,
-    )(streams)
+    )(streams)[:, 0]

@@ -33,7 +33,9 @@ def sne_encode(
     """
     assert n_bits % 32 == 0, "kernel path packs whole uint32 words"
     interpret = backend.resolve_interpret(interpret)
-    use_kernel = backend.resolve_use_kernel(use_kernel, interpret)
+    # the TPU compiler (Mosaic) refuses this kernel (float32 -> uint32 casts,
+    # uint32 reductions): the bit-exact reference is the default everywhere
+    use_kernel = bool(use_kernel)
     p = jnp.asarray(p, jnp.float32)
     flat = p.reshape(-1)
     n_rand = n_bits // 4  # 4 bytes (stream bits) per random word

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.kernels import backend
 from repro.models import api
 from repro.serve import EngineConfig, Request, ServeEngine
 
@@ -34,6 +35,7 @@ def main():
                  f"(got {args.gate_bits}); the packed pipeline consumes whole "
                  f"uint32 entropy words")
 
+    backend.enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = api.init(cfg, jax.random.PRNGKey(0))
     engine = ServeEngine(

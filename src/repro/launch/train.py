@@ -15,6 +15,7 @@ import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig
+from repro.kernels import backend
 from repro.optim import adamw
 from repro.train.loop import TrainConfig, TrainLoop
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
 
+    backend.enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     data_cfg = DataConfig(
         seed=0, global_batch=args.global_batch, seq_len=args.seq_len,

@@ -25,8 +25,7 @@ the Chrome trace event format (the JSON flavour Perfetto and
 span, ``"i"`` instants for point events, and ``"M"`` metadata naming each
 track.  ``Tracer(annotate=True)`` additionally wraps sync spans in
 ``jax.profiler.TraceAnnotation`` so the same region names land inside an XLA
-profiler trace when one is being captured; the import is lazy and failure
-degrades to plain spans (the obs layer itself never requires jax).
+profiler trace when one is being captured.
 """
 
 from __future__ import annotations
@@ -36,6 +35,8 @@ import json
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _CURRENT = object()  # default parent sentinel: "whatever span is open"
 
@@ -78,7 +79,6 @@ class Tracer:
         self._spans: List[Span] = []
         self._stack: List[int] = []
         self._annotate = annotate
-        self._annotation_cls = None  # resolved lazily on first sync span
 
     # -------------------------------------------------------------- recording
     def begin(
@@ -122,10 +122,9 @@ class Tracer:
         """Nested sync span: parented by the enclosing open span."""
         sid = self.begin(name, parent=parent, track=track, **attrs)
         self._stack.append(sid)
-        annotation = self._resolve_annotation(name)
         try:
-            if annotation is not None:
-                with annotation:
+            if self._annotate:
+                with TraceAnnotation(name):
                     yield self._spans[sid]
             else:
                 yield self._spans[sid]
@@ -140,19 +139,6 @@ class Tracer:
         sp.t_end = sp.t_start
         sp.instant = True
         return sid
-
-    def _resolve_annotation(self, name: str):
-        if not self._annotate:
-            return None
-        if self._annotation_cls is None:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._annotation_cls = TraceAnnotation
-            except Exception:  # jax absent or too old: degrade silently
-                self._annotate = False
-                return None
-        return self._annotation_cls(name)
 
     # -------------------------------------------------------------- querying
     @property

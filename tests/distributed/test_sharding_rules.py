@@ -11,11 +11,11 @@ from repro.models import api
 
 
 def fake_mesh(shape=(16, 16), axes=("data", "model")):
-    # Spec computation needs no real devices: AbstractMesh takes
-    # ((name, size), ...) pairs and exposes axis_names/axis_sizes/shape.
+    # Spec computation needs no real devices: AbstractMesh takes the axis
+    # sizes and names and exposes axis_names/axis_sizes/shape.
     from jax.sharding import AbstractMesh
 
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def test_param_specs_qwen_rules():
