@@ -145,7 +145,7 @@ def run_frame_budget(artifact_dir: str = ".") -> None:
         )
 
     # traced FrameDriver run -> Perfetto artifact.  96 frames through a
-    # 32-lane async driver = 3 pipelined launches; the exported `device`
+    # 32-lane async driver = 3 pipelined launches; the exported `launch`
     # spans overlap, which is the async pipeline made visible.  The driver
     # shares the registry above, so its frame_ms / launch_ms / watchdog
     # histograms land in the same latency_hist.csv.

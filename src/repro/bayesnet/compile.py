@@ -53,8 +53,9 @@ bit-identical to single-device output on every scenario.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
-from typing import Callable, Sequence, Tuple
+from typing import Callable, Iterator, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -226,6 +227,10 @@ class CompiledNetwork:
     launch spans ``n_shards`` devices over the frame axis (``shard_axes``),
     bit-identical to the single-device program for any batch the shard count
     divides (indivisible batches transparently run the single-device path).
+
+    ``trace`` (a :class:`~repro.obs.Tracer`, set by ``compile_network(trace=)``)
+    makes every ``run`` / ``decide`` call record its span tree (see
+    :meth:`decide`); ``None`` leaves both calls untouched.
     """
 
     spec: NetworkSpec
@@ -246,6 +251,11 @@ class CompiledNetwork:
     # (calibrate-back compensation; None = clean spec thresholds).
     drift_epochs: int = 1
     program: dict | None = dataclasses.field(default=None, repr=False, compare=False)
+    trace: Tracer | None = dataclasses.field(default=None, repr=False, compare=False)
+    # per-network call index of traced calls (the ``launch`` span attr)
+    _calls: Iterator[int] = dataclasses.field(
+        default_factory=itertools.count, init=False, repr=False, compare=False
+    )
 
     def _check_frames(self, ev_frames) -> jnp.ndarray:
         ev = jnp.asarray(ev_frames, jnp.int32)
@@ -255,8 +265,37 @@ class CompiledNetwork:
             )
         return ev
 
+    def _traced_call(self, name: str, fn: Callable, key: jax.Array, ev_frames):
+        """One call of ``fn`` under a ``name`` span with two sync children.
+
+        ``<name>.evidence`` covers the host evidence -> device array
+        conversion, ``<name>.dispatch`` the jitted call (enqueue only, under
+        async dispatch; its ``compiled`` attr is true when the call grew the
+        jit's cache, which also adds 1 to the tracer's ``<name>.compiles``
+        count).  The parent's attrs are counts at the layer boundary taken
+        from shapes, never from a device sync.
+        """
+        tr = self.trace
+        with tr.span(name, network=self.spec.name, launch=next(self._calls)) as sp:
+            with tr.span(f"{name}.evidence"):
+                ev = self._check_frames(ev_frames)
+            n_cached = fn._cache_size()
+            with tr.span(f"{name}.dispatch") as dsp:
+                out = fn(key, ev)
+            compiled = fn._cache_size() > n_cached
+            dsp.attrs["compiled"] = compiled
+            if compiled:
+                tr.inc(f"{name}.compiles")
+            sp.attrs.update(
+                frames=ev.shape[0], in_bytes=ev.nbytes + key.nbytes,
+                out_arrays=len(out), out_bytes=sum(x.nbytes for x in out),
+            )
+        return out
+
     def run(self, key: jax.Array, ev_frames) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        return self._run(key, self._check_frames(ev_frames))
+        if self.trace is None:
+            return self._run(key, self._check_frames(ev_frames))
+        return self._traced_call("run", self._run, key, ev_frames)
 
     def decide(
         self, key: jax.Array, ev_frames
@@ -270,8 +309,16 @@ class CompiledNetwork:
         (:func:`posterior_argmax`); both produce the decision a MAP readout
         of ``run``'s posterior would, bit-for-bit.  Returns
         ``(post, decisions (B, n_q) int32, accepted)``.
+
+        With ``trace`` set, each call records a ``decide`` span (attrs
+        ``network``, ``frames``, ``launch``, ``in_bytes``, ``out_arrays``,
+        ``out_bytes``) over ``decide.evidence`` and ``decide.dispatch``
+        children, and counts recompiles in ``decide.compiles``; ``run``
+        records the same tree under ``run``.  The outputs are bit-identical.
         """
-        return self._decide(key, self._check_frames(ev_frames))
+        if self.trace is None:
+            return self._decide(key, self._check_frames(ev_frames))
+        return self._traced_call("decide", self._decide, key, ev_frames)
 
 
 def sweep_plan(
@@ -508,7 +555,9 @@ def compile_network(
     threshold-mask bytes, value slots).  The span's duration is the
     *lowering* time -- plan construction + jit wrapper building; XLA
     compilation itself is lazy and shows up inside the first launch's
-    ``dispatch`` span instead.  ``trace=None`` changes nothing.
+    ``decide.dispatch`` (or ``run.dispatch``) span instead: the returned
+    program keeps the tracer and records every call.  ``trace=None``
+    changes nothing.
     """
     if trace is not None:
         with trace.span("compile_network", network=spec.name, n_bits=n_bits) as sp:
@@ -519,7 +568,7 @@ def compile_network(
                 devices=devices, use_kernel=use_kernel, interpret=interpret,
             )
             sp.attrs.update(network_stats(net))
-            return net
+            return dataclasses.replace(net, trace=trace)
     queries = tuple(queries if queries is not None else spec.queries)
     evidence = tuple(evidence if evidence is not None else spec.evidence)
     if not queries:

@@ -65,13 +65,13 @@ host-side latency a serving deployment cares about.
 **Telemetry.**  ``trace=Tracer()`` / ``metrics=MetricsRegistry()``
 (:mod:`repro.obs`) light up the whole serving path with zero behaviour
 change -- the traced driver's posteriors are bit-identical to the untraced
-one's (a regression-tested property, like the <=5% overhead bound).  Each
+one's (a regression-tested property, like its fixed span count a launch).  Each
 launch becomes a span tree honouring jax's async dispatch: a ``launch[n]``
-parent span from dispatch to harvest, ``pack`` and ``dispatch`` sync child
-spans for the host-side work, a ``device`` child opened when the dispatch
-call returns and closed only when :meth:`harvest` first blocks on the result
-(overlapping ``device`` spans in the exported trace ARE the async pipeline),
-and a ``harvest`` child for host-side conversion + confidence gating.
+parent span from dispatch to harvest, closed only after :meth:`harvest`
+has blocked on the result (overlapping ``launch`` spans in the exported
+trace ARE the async pipeline), ``pack`` and ``dispatch`` sync child spans
+for the host-side work, and a ``harvest`` child for host-side conversion +
+confidence gating.
 Retried frames get ``retry[rid]`` spans nested under the launch that flagged
 them, covering the wait until their re-launch's verdict.  The registry
 counts frames in/out, launches, per-bucket launch shapes, padded lanes,
@@ -146,7 +146,6 @@ class _InFlight:
     post: object                     # device posteriors (None for a dropped launch)
     accepted: object                 # device accepted counts (None when dropped)
     lspan: Optional[int]             # launch span id
-    dspan: Optional[int]             # device span id
     t_dispatch: Optional[float]      # dispatch wall-clock
     n_bits: int                      # stream length of the plan that dispatched
     fault: Optional[str] = None      # injected fault kind, if any
@@ -366,7 +365,7 @@ class FrameDriver:
     def _launch(self, key: jax.Array | None, taken: list, attempt: int) -> int:
         """Pack one batch at one attempt level, launch it, park the results."""
         tr, mx = self.trace, self.metrics
-        lspan = dspan = t_dispatch = None
+        lspan = t_dispatch = None
         if tr is not None:
             lspan = tr.begin(
                 f"launch[{self._dispatches}]", track="launch",
@@ -399,7 +398,7 @@ class FrameDriver:
         elif tr is not None:
             # host-side dispatch only: under async dispatch net.run returns
             # as soon as the work is enqueued, so this span is trace/compile
-            # lookup + enqueue -- the device interval is the `device` span
+            # lookup + enqueue; the launch span stays open until harvest
             with tr.span("dispatch", parent=lspan, bucket=ev.shape[0]):
                 post, accepted = net.run(key, ev)
         else:
@@ -409,8 +408,6 @@ class FrameDriver:
         if self.watch.step_end(ticket):
             self.stats.slow_launches += 1
         self.stats.launches += 1
-        if tr is not None:
-            dspan = tr.begin("device", parent=lspan, track="device", ticket=ticket)
         if mx is not None:
             mx.inc("launches")
             mx.inc(f"bucket_{ev.shape[0]}")
@@ -427,7 +424,7 @@ class FrameDriver:
             mx.set_gauge("in_flight", len(self._inflight) + 1)
             mx.set_gauge("pending", len(self._queue))
         self._inflight.append(
-            _InFlight(ticket, taken, attempt, post, accepted, lspan, dspan,
+            _InFlight(ticket, taken, attempt, post, accepted, lspan,
                       t_dispatch, net.n_bits, fault=injected)
         )
         return ticket
@@ -498,10 +495,6 @@ class FrameDriver:
             # dropped launch: nothing was ever enqueued
             raise LaunchFault("drop", lf.ticket, "launch produced no result")
         post, accepted = np.asarray(lf.post), np.asarray(lf.accepted)
-        if tr is not None:
-            # first observable point at which this launch's device work
-            # is complete: the host just blocked on its arrays
-            tr.end(lf.dspan)
         if lf.fault == "corrupt":
             # injected buffer corruption: validation below must catch it
             post = np.full_like(post, np.nan)
@@ -606,7 +599,7 @@ class FrameDriver:
         tr, mx = self.trace, self.metrics
         kind = lf.fault or getattr(exc, "kind", None) or "error"
         if tr is not None:
-            for sid in (lf.hspan, lf.dspan, lf.lspan):
+            for sid in (lf.hspan, lf.lspan):
                 if sid is not None and not tr.get(sid).done:
                     tr.end(sid, error=kind)
         self.launch_failures.append(

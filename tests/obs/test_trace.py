@@ -103,6 +103,14 @@ class TestAsyncSpans:
         assert [s.name for s in tr.named("launch")] == ["launch[0]", "launch[1]"]
         assert tr.span_counts() == {"launch[0]": 1, "launch[1]": 1, "submit": 1}
 
+    def test_named_counts_start_at_zero_and_add(self):
+        tr = Tracer()
+        assert tr.count("decide.compiles") == 0
+        assert tr.inc("decide.compiles") == 1
+        assert tr.inc("decide.compiles", 2) == 3
+        assert tr.counters == {"decide.compiles": 3}
+        assert tr.spans == []   # counts are not spans
+
 
 class TestChromeTraceExport:
     def _trace(self):
