@@ -190,6 +190,21 @@ def _count_assembler(q_cards: Tuple[int, ...]) -> Callable:
     return assemble
 
 
+def _start_host_copies(arrays) -> int:
+    """Start each concrete array's device -> host copy; returns how many.
+
+    ``copy_to_host_async`` enqueues the copy of every addressable shard and
+    returns at once; a later ``np.asarray`` reads the copied value.  Tracers
+    (a call under ``jit`` / ``vmap``) have no device buffer and are skipped.
+    """
+    n = 0
+    for x in arrays:
+        if not isinstance(x, jax.core.Tracer):
+            x.copy_to_host_async()
+            n += 1
+    return n
+
+
 def posterior_argmax(post: jnp.ndarray) -> jnp.ndarray:
     """MAP decision from a ``run`` posterior, matching the fused epilogue.
 
@@ -265,15 +280,21 @@ class CompiledNetwork:
             )
         return ev
 
-    def _traced_call(self, name: str, fn: Callable, key: jax.Array, ev_frames):
+    def _traced_call(
+        self, name: str, fn: Callable, key: jax.Array, ev_frames,
+        host_copies: bool = False,
+    ):
         """One call of ``fn`` under a ``name`` span with two sync children.
 
         ``<name>.evidence`` covers the host evidence -> device array
         conversion, ``<name>.dispatch`` the jitted call (enqueue only, under
         async dispatch; its ``compiled`` attr is true when the call grew the
         jit's cache, which also adds 1 to the tracer's ``<name>.compiles``
-        count).  The parent's attrs are counts at the layer boundary taken
-        from shapes, never from a device sync.
+        count).  With ``host_copies`` the outputs' device -> host copies are
+        started after ``<name>.dispatch`` closes, inside the parent span, and
+        their number is added to the ``<name>.host_copies`` count.  The
+        parent's attrs are counts at the layer boundary taken from shapes,
+        never from a device sync.
         """
         tr = self.trace
         with tr.span(name, network=self.spec.name, launch=next(self._calls)) as sp:
@@ -286,6 +307,8 @@ class CompiledNetwork:
             dsp.attrs["compiled"] = compiled
             if compiled:
                 tr.inc(f"{name}.compiles")
+            if host_copies:
+                tr.inc(f"{name}.host_copies", _start_host_copies(out))
             sp.attrs.update(
                 frames=ev.shape[0], in_bytes=ev.nbytes + key.nbytes,
                 out_arrays=len(out), out_bytes=sum(x.nbytes for x in out),
@@ -310,15 +333,28 @@ class CompiledNetwork:
         of ``run``'s posterior would, bit-for-bit.  Returns
         ``(post, decisions (B, n_q) int32, accepted)``.
 
+        The three outputs come back as ``jax.Array``s without waiting on the
+        device, with their device -> host copies already started: a caller's
+        ``np.asarray`` finds each copy in flight or done instead of starting
+        it, so the copies overlap one another and the caller's own work.
+        Outputs of a call made under a JAX transformation are tracers and are
+        returned untouched.
+
         With ``trace`` set, each call records a ``decide`` span (attrs
         ``network``, ``frames``, ``launch``, ``in_bytes``, ``out_arrays``,
         ``out_bytes``) over ``decide.evidence`` and ``decide.dispatch``
-        children, and counts recompiles in ``decide.compiles``; ``run``
-        records the same tree under ``run``.  The outputs are bit-identical.
+        children, counts recompiles in ``decide.compiles`` and the copies it
+        started in ``decide.host_copies`` (3 a call); ``run`` records the same
+        tree under ``run`` and starts no copies.  The outputs are
+        bit-identical.
         """
         if self.trace is None:
-            return self._decide(key, self._check_frames(ev_frames))
-        return self._traced_call("decide", self._decide, key, ev_frames)
+            out = self._decide(key, self._check_frames(ev_frames))
+            _start_host_copies(out)
+            return out
+        return self._traced_call(
+            "decide", self._decide, key, ev_frames, host_copies=True
+        )
 
 
 def sweep_plan(

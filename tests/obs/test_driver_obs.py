@@ -250,6 +250,28 @@ class TestDecideTracing:
         assert tr.count("decide.compiles") == 2
 
     @pytest.mark.parametrize("fused", [True, False])
+    def test_decide_counts_three_host_copies_a_call(self, pn_ev, fused):
+        tr = Tracer()
+        net = compile_network(
+            by_name("pedestrian-night"), n_bits=N_BITS, fused=fused, trace=tr
+        )
+        key = jax.random.PRNGKey(5)
+        for calls in (1, 2, 3):
+            net.decide(key, pn_ev)
+            assert tr.count("decide.host_copies") == 3 * calls
+        # the span set of a launch is unchanged by the copies
+        assert tr.span_counts() == {
+            "compile_network": 1, "decide": 3, "decide.evidence": 3,
+            "decide.dispatch": 3,
+        }
+        for dec in (s for s in tr.spans if s.name == "decide"):
+            kids = [s.name for s in tr.spans if s.parent_id == dec.span_id]
+            assert kids == ["decide.evidence", "decide.dispatch"]
+        net.run(key, pn_ev)   # run starts no copies
+        assert tr.count("decide.host_copies") == 9
+        assert "run.host_copies" not in tr.counters
+
+    @pytest.mark.parametrize("fused", [True, False])
     def test_trace_none_records_nothing_and_is_bit_identical(self, pn_ev, fused):
         spec = by_name("pedestrian-night")
         tr = Tracer()
