@@ -77,7 +77,7 @@ def test_new_config_mix_and_metric_from_new_files_alone(tmp_path):
     doc["workloads"].append({"name": "two-nets.deep", "config": "two-nets",
                              "traffic": "deep", "chips": 1, "why": "test"})
     for m in doc["end_to_end"]:
-        if "workloads" in m:
+        if "fleet-edge.replay" in m.get("workloads", ()):
             m["workloads"].append("two-nets.deep")
     doc["per_layer"].append({"name": "bench.launches", "unit": "count", "better": "higher",
                              "source": "program_counter", "layer": "benchmark",
@@ -113,6 +113,18 @@ def _trace():
     ("program.compile_s", {"compile_setup_s": 2.25}, 2.25),
     ("device.idle_pct.replay", {"device_trace": _trace()}, 25.0),
     ("net_sweep.gnodebits_per_s", {"device_trace": _trace(), "launch_bits": 8e9}, 4.0),
+    ("device.idle_pct.served", {"device_trace": _trace()}, 25.0),
+    ("latency_p50_ms", {"latency_ms": [4.0, 1.0, 3.0, 2.0]}, 2.5),
+    ("latency_p95_ms", {"latency_ms": [float(x) for x in range(101)]}, 95.0),
+    ("served.lag_p99_ms", {"lag_ms": [0.5] * 99 + [100.5]}, 1.5),
+    ("router.pump_us_per_decision",
+     {"program_spans": [("router.pump", 1.0, 1.003), ("pack", 1.0, 1.001), ("router.pump", 2.0, 2.001)],
+      "answered": 8}, 500.0),
+    ("driver.host_us_per_launch",
+     {"program_spans": [("launch[0]", 0.0, 0.01), ("pack", 0.0, 0.0001), ("dispatch", 0.001, 0.002),
+                        ("harvest", 0.003, 0.0049), ("launch[1]", 1.0, 1.01), ("run", 1.0, 1.5)]},
+     1500.0),
+    ("driver.frames_per_launch", {"program_counts": {"launches": 4, "frames_out": 22}}, 5.5),
 ])
 def test_metric_readers_on_a_record(name, fields, value):
     rec = harness.RunRecord({}, {}, {}, 1.0, True)
