@@ -12,7 +12,8 @@ stochastic domain (DESIGN.md SS8-SS10).
     noise.py      NoiseModel: plan-build-time device-to-device / read-noise /
                   IR-drop / stuck-at perturbation of the DAC thresholds
     analytic.py   exact mixed-radix enumeration oracle + ancestral sampling;
-                  noise= builds the perturbed-CPT oracle twin
+                  noise= builds the perturbed-CPT oracle twin;
+                  filter_posteriors is the tracked networks' reference
     reliability.py decision-margin confidence signal, RetryPolicy, and the
                   flip-rate / harvest reliability statistics
     scenarios.py  5-12 node driving networks over data/detection statistics
@@ -28,7 +29,11 @@ stochastic domain (DESIGN.md SS8-SS10).
                   hot recalibration of live drivers (DESIGN §15)
 """
 
-from repro.bayesnet.analytic import make_posterior_fn, sample_evidence  # noqa: F401
+from repro.bayesnet.analytic import (  # noqa: F401
+    filter_posteriors,
+    make_posterior_fn,
+    sample_evidence,
+)
 from repro.bayesnet.calibrate import (  # noqa: F401
     calibration_report,
     compensated_program,

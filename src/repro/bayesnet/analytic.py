@@ -26,6 +26,9 @@ acts on the integer DAC grid, so ``noise`` subsumes ``dac_quantize``: the
 perturbed thresholds ARE the quantisation, and the flag is ignored when a
 model is given.
 
+:func:`filter_posteriors` is the plain reference of a *tracked* spec: one
+exact enumeration step per frame under the prior the tracker predicts for it.
+
 Posterior layout mirrors the compiler: all-binary query sets keep the classic
 ``(B, n_q)`` array of ``P(q=1)``; any k-ary query switches to ``(B, n_q,
 max_k)`` normalised per-value posteriors (zero-padded past each query's
@@ -273,3 +276,116 @@ def sample_evidence(
     full = _sample_joint(spec, key, batch)
     cols = jnp.asarray([spec.index(e) for e in evidence], jnp.int32)
     return full[:, cols]
+
+
+def filter_posteriors(
+    spec: NetworkSpec,
+    evidence,
+    track_ids,
+    fresh,
+    answered_posts=None,
+    answered_accepted=None,
+):
+    """Exact filtering reference of a tracked spec: one step per frame.
+
+    ``evidence`` (F, n_ev) int rows in ``spec.evidence`` order, ``track_ids``
+    (F,) and ``fresh`` (F,) as the tracked program takes them, frames in the
+    order they are decided.  Returns ``(post, p_e, prior)``: the posterior
+    of each frame in :func:`make_posterior_fn`'s layout, its exact evidence
+    probability, and the (F, k) DAC-quantised prior it was decided under.
+
+    Per frame, in float64 numpy over the full joint: the predicted prior is
+    the published prior for a fresh track; otherwise the track's previous
+    belief times the transition.  The previous belief is the track's
+    previous answered posterior over the state when ``answered_posts`` (the
+    program's outputs, same layout) and ``answered_accepted`` (their
+    accepted counts) are given, else the exact previous posterior.  Every
+    other CPT row is DAC-quantised as in :func:`make_posterior_fn`
+    (``dac_quantize=True``).
+
+    Two departures from a textbook Bayes filter, both the tracked program's:
+
+    * the predicted prior is quantised with the same 8-bit DAC as every CPT
+      row (``rng.cdf_thresholds_int``) before the frame is decided, so each
+      step conditions on a prior on the ``t / 256`` grid, not the exact one;
+    * a frame that accepted no sample (an all-zero answered posterior, or
+      evidence of probability 0) keeps the unquantised prediction as its
+      track's belief: an empty sample leaves only the transition's move.
+    """
+    if spec.state is None:
+        raise ValueError(f"{spec.name}: filter_posteriors needs a tracked spec")
+    if (answered_posts is None) != (answered_accepted is None):
+        raise ValueError("answered_posts and answered_accepted come together")
+    root, trans = spec.state
+    k = spec.card(root)
+    trans = np.asarray(trans, np.float64)
+    published = np.asarray(spec.cpt_rows(root)[0], np.float64)
+    queries = spec.queries
+    q_cards = tuple(spec.card(q) for q in queries)
+    all_binary = all(c == 2 for c in q_cards)
+    kmax = max(q_cards)
+    cards = spec.cards()
+    total = math.prod(cards)
+    if total > _MAX_STATES:
+        raise ValueError(
+            f"enumeration oracle capped at {_MAX_STATES} joint states, got {total}"
+        )
+    s = np.arange(total, dtype=np.int64)
+    states = []
+    for c in cards:
+        states.append(s % c)
+        s //= c
+    states = np.stack(states, axis=-1)
+    idx = {node.name: j for j, node in enumerate(spec.nodes)}
+    rest = np.ones(total, np.float64)          # every factor but the state root's
+    for node in spec.nodes:
+        if node.name == root:
+            continue
+        cpt = _node_rows(spec, node.name, True).astype(np.float64)
+        row = np.zeros(total, np.int64)
+        for parent in node.parents:
+            row = row * spec.card(parent) + states[:, idx[parent]]
+        rest *= cpt[row, states[:, idx[node.name]]]
+    root_vals = states[:, idx[root]]
+    ev_states = states[:, [idx[e] for e in spec.evidence]]
+    q_states = states[:, [idx[q] for q in queries]]
+    state_q = queries.index(root)
+
+    evidence = np.asarray(evidence, np.int64).reshape(len(track_ids), -1)
+    n = len(evidence)
+    post = np.zeros((n, len(queries)) if all_binary else (n, len(queries), kmax))
+    p_e = np.zeros(n)
+    priors = np.zeros((n, k))
+    belief = {}
+    for f in range(n):
+        t = int(track_ids[f])
+        if fresh[f]:
+            pred = published
+        elif t in belief:
+            pred = belief[t] @ trans
+        else:
+            raise ValueError(f"frame {f}: track {t} continues but never started")
+        bounds = (256,) + rng.cdf_thresholds_int(tuple(pred)) + (0,)
+        prior = np.asarray([(bounds[v] - bounds[v + 1]) / 256.0 for v in range(k)])
+        priors[f] = prior
+        w = rest * prior[root_vals] * np.all(ev_states == evidence[f], axis=-1)
+        p_e[f] = w.sum()
+        for qi, c in enumerate(q_cards):
+            mass = np.bincount(q_states[:, qi], weights=w, minlength=c)
+            p = mass / p_e[f] if p_e[f] > 0 else np.full(c, 1.0 / c)
+            if all_binary:
+                post[f, qi] = p[1]
+            else:
+                post[f, qi, :c] = p
+        if answered_posts is None:
+            b = (post[f, state_q, :k] if not all_binary
+                 else np.asarray([1 - post[f, state_q], post[f, state_q]]))
+            belief[t] = b if p_e[f] > 0 else pred
+        elif answered_accepted[f] > 0:
+            a = np.asarray(answered_posts[f], np.float64)
+            belief[t] = a[state_q, :k] if a.ndim == 2 else np.asarray(
+                [1 - a[state_q], a[state_q]])
+        else:
+            belief[t] = pred
+    return post, p_e, priors
+

@@ -48,17 +48,33 @@ results by construction.
 fused launch over the frame axis with ``shard_map``; the global frame index
 is folded into the per-frame entropy counters, so sharded output is
 bit-identical to single-device output on every scenario.
+
+**Tracked networks.**  A spec with a ``state`` (a root carried from frame to
+frame, :mod:`repro.bayesnet.spec`) compiles to a fused program whose ``run``
+and ``decide`` also take a belief table held on the device -- ``max_tracks x
+k`` float32, one row per live track (:meth:`CompiledNetwork.new_table`) --
+with each frame's row index and a fresh-track flag.  Inside the one jitted
+call the program gathers the frames' rows, predicts each frame's prior (the
+published prior for a fresh track, ``belief @ transition`` otherwise,
+float32), quantises it with the same 8-bit DAC as every CPT row, sweeps with
+those thresholds in place of the root's CPT row, and scatters each frame's
+posterior over the state back into its row (the prediction it used, when it
+accepted no sample).  The table is donated and returned.  A launch holds at
+most one frame of a track; rows past the table (the driver's padding lanes)
+read the published prior and write nothing.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 from typing import Callable, Iterator, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.bayesnet.noise import NoiseModel, perturbed_cdf_rows
@@ -190,6 +206,11 @@ def _count_assembler(q_cards: Tuple[int, ...]) -> Callable:
     return assemble
 
 
+def _array_module(x):
+    """``jnp`` for a device array or a tracer, ``np`` for anything on the host."""
+    return jnp if isinstance(x, jax.Array) else np
+
+
 def _start_host_copies(arrays) -> int:
     """Start each concrete array's device -> host copy; returns how many.
 
@@ -246,6 +267,12 @@ class CompiledNetwork:
     ``trace`` (a :class:`~repro.obs.Tracer`, set by ``compile_network(trace=)``)
     makes every ``run`` / ``decide`` call record its span tree (see
     :meth:`decide`); ``None`` leaves both calls untouched.
+
+    A tracked network (``spec.state`` set) takes three more arguments,
+    ``table`` (``new_table(max_tracks)``, donated), ``track_idx`` (B,) int
+    and ``fresh`` (B,) bool, and returns the updated table last:
+    ``run -> (post, accepted, table)``, ``decide -> (post, decisions,
+    accepted, table)``.
     """
 
     spec: NetworkSpec
@@ -272,17 +299,57 @@ class CompiledNetwork:
         default_factory=itertools.count, init=False, repr=False, compare=False
     )
 
-    def _check_frames(self, ev_frames) -> jnp.ndarray:
-        ev = jnp.asarray(ev_frames, jnp.int32)
+    def _check_frames(self, ev_frames, xp=jnp) -> jnp.ndarray:
+        ev = xp.asarray(ev_frames, xp.int32)
         if ev.ndim != 2 or ev.shape[1] != len(self.evidence):
             raise ValueError(
                 f"evidence frames must be (B, {len(self.evidence)}), got {ev.shape}"
             )
         return ev
 
+    @property
+    def tracked(self) -> bool:
+        """True when the network carries a state root from frame to frame."""
+        return self.spec.state is not None
+
+    def new_table(self, max_tracks: int) -> jax.Array:
+        """An empty belief table: ``max_tracks`` rows of the state's ``k`` values.
+
+        Rows are read only by frames of continuing tracks; a track's first
+        frame (``fresh``) starts from the published prior and fills its row.
+        """
+        if not self.tracked:
+            raise ValueError(f"{self.spec.name}: an untracked network has no belief table")
+        if max_tracks < 1:
+            raise ValueError(f"max_tracks must be >= 1, got {max_tracks}")
+        return jnp.zeros((int(max_tracks), self.spec.card(self.spec.state_node)), jnp.float32)
+
+    def _args(self, ev_frames, table, track_idx, fresh) -> tuple:
+        """The jitted program's arguments after the key, checked."""
+        if not self.tracked:
+            if table is not None or track_idx is not None or fresh is not None:
+                raise ValueError(f"{self.spec.name}: an untracked network takes no track state")
+            return (self._check_frames(ev_frames),)
+        if table is None or track_idx is None or fresh is None:
+            raise ValueError(
+                f"{self.spec.name}: a tracked network needs table, track_idx and fresh"
+            )
+        # host inputs stay host arrays: the jitted call moves them to the
+        # device in its own dispatch, where a device put of each costs a
+        # transfer of its own; device arrays and tracers stay as they are
+        ev = self._check_frames(ev_frames, _array_module(ev_frames))
+        idx = _array_module(track_idx).asarray(track_idx, np.int32)
+        new = _array_module(fresh).asarray(fresh, np.bool_)
+        if idx.shape != (ev.shape[0],) or new.shape != (ev.shape[0],):
+            raise ValueError(
+                f"track_idx {idx.shape} and fresh {new.shape} need one entry per frame "
+                f"({ev.shape[0]})"
+            )
+        return (ev, table, idx, new)
+
     def _traced_call(
-        self, name: str, fn: Callable, key: jax.Array, ev_frames,
-        host_copies: bool = False,
+        self, name: str, fn: Callable, key: jax.Array, ev_frames, tracks=(None,) * 3,
+        host_copies: int = 0,
     ):
         """One call of ``fn`` under a ``name`` span with two sync children.
 
@@ -290,7 +357,7 @@ class CompiledNetwork:
         conversion, ``<name>.dispatch`` the jitted call (enqueue only, under
         async dispatch; its ``compiled`` attr is true when the call grew the
         jit's cache, which also adds 1 to the tracer's ``<name>.compiles``
-        count).  With ``host_copies`` the outputs' device -> host copies are
+        count).  The first ``host_copies`` outputs' device -> host copies are
         started after ``<name>.dispatch`` closes, inside the parent span, and
         their number is added to the ``<name>.host_copies`` count.  The
         parent's attrs are counts at the layer boundary taken from shapes,
@@ -299,30 +366,43 @@ class CompiledNetwork:
         tr = self.trace
         with tr.span(name, network=self.spec.name, launch=next(self._calls)) as sp:
             with tr.span(f"{name}.evidence"):
-                ev = self._check_frames(ev_frames)
+                args = self._args(ev_frames, *tracks)
+            ev = args[0]
             n_cached = fn._cache_size()
             with tr.span(f"{name}.dispatch") as dsp:
-                out = fn(key, ev)
+                out = fn(key, *args)
             compiled = fn._cache_size() > n_cached
             dsp.attrs["compiled"] = compiled
             if compiled:
                 tr.inc(f"{name}.compiles")
             if host_copies:
-                tr.inc(f"{name}.host_copies", _start_host_copies(out))
+                tr.inc(f"{name}.host_copies", _start_host_copies(out[:host_copies]))
             sp.attrs.update(
                 frames=ev.shape[0], in_bytes=ev.nbytes + key.nbytes,
                 out_arrays=len(out), out_bytes=sum(x.nbytes for x in out),
             )
         return out
 
-    def run(self, key: jax.Array, ev_frames) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        if self.trace is None:
-            return self._run(key, self._check_frames(ev_frames))
-        return self._traced_call("run", self._run, key, ev_frames)
+    def run(self, key: jax.Array, ev_frames, table=None, track_idx=None, fresh=None):
+        """Posteriors and accepted counts of one launch: ``(post, accepted)``.
 
-    def decide(
-        self, key: jax.Array, ev_frames
-    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        A tracked network's ``run`` returns its table last and, as ``decide``
+        does, starts the device -> host copies of ``post`` and ``accepted``
+        before it returns (``run.host_copies``, 2 a call, when traced): its
+        driver fetches both of every launch, and the table stays on the
+        device.  An untracked ``run`` starts no copies.
+        """
+        tracks = (table, track_idx, fresh)
+        copies = 2 if self.tracked else 0
+        if self.trace is None:
+            out = self._run(key, *self._args(ev_frames, *tracks))
+            if copies:
+                _start_host_copies(out[:copies])
+            return out
+        return self._traced_call("run", self._run, key, ev_frames, tracks,
+                                 host_copies=copies)
+
+    def decide(self, key: jax.Array, ev_frames, table=None, track_idx=None, fresh=None):
         """Posteriors AND per-query MAP decisions in the same launch.
 
         Fused programs run ``net_sweep(..., decide=True)``: the decision
@@ -345,15 +425,17 @@ class CompiledNetwork:
         ``out_bytes``) over ``decide.evidence`` and ``decide.dispatch``
         children, counts recompiles in ``decide.compiles`` and the copies it
         started in ``decide.host_copies`` (3 a call); ``run`` records the same
-        tree under ``run`` and starts no copies.  The outputs are
-        bit-identical.
+        tree under ``run`` (see :meth:`run` for its copies).  The outputs are
+        bit-identical.  A tracked network's table comes back last, left on
+        the device.
         """
+        tracks = (table, track_idx, fresh)
         if self.trace is None:
-            out = self._decide(key, self._check_frames(ev_frames))
-            _start_host_copies(out)
+            out = self._decide(key, *self._args(ev_frames, *tracks))
+            _start_host_copies(out[:3])
             return out
         return self._traced_call(
-            "decide", self._decide, key, ev_frames, host_copies=True
+            "decide", self._decide, key, ev_frames, tracks, host_copies=3
         )
 
 
@@ -384,7 +466,9 @@ def sweep_plan(
     epoch_word_bounds`).  ``drift_epochs=1`` produces exactly the
     single-snapshot plan.  ``program`` overrides the programmed thresholds
     fed into the perturbation (calibrate-back compensation, see
-    :func:`~repro.bayesnet.noise.perturbed_cdf_rows`).
+    :func:`~repro.bayesnet.noise.perturbed_cdf_rows`).  A tracked spec's
+    state root becomes the plan's ``state``: its thresholds then come per
+    frame, and its CPT row (the published prior) is what fresh tracks use.
     """
     drift_epochs = int(drift_epochs)
     if drift_epochs > 1 and noise is None:
@@ -415,6 +499,7 @@ def sweep_plan(
         queries=tuple(index[q] for q in queries),
         epochs=drift_epochs,
         epoch_rows=tuple(epoch_rows),
+        state=None if spec.state is None else index[spec.state_node],
     )
 
 
@@ -513,6 +598,69 @@ def lower_streams(
             )
             streams[name] = tuple(planes[b] for b in range(planes.shape[0]))
     return streams
+
+
+def carry_belief(post_state: jnp.ndarray, pred: jnp.ndarray,
+                 accepted: jnp.ndarray) -> jnp.ndarray:
+    """The row a frame leaves in its track's table entry.
+
+    Its posterior over the state when it accepted a sample; the prediction it
+    used when it accepted none (an empty sample has no posterior: the
+    belief then only moves by the transition).
+    """
+    return jnp.where(accepted[:, None] > 0, post_state, pred)
+
+
+def _tracked_programs(spec: NetworkSpec, queries, plan: SweepPlan,
+                      assemble_counts: Callable, sweep_kwargs: dict):
+    """The jitted ``run`` / ``decide`` of a tracked network (table donated).
+
+    Each call: gather the frames' table rows, predict (published prior for a
+    fresh track, else ``belief @ transition`` in float32), quantise the
+    prediction with the 8-bit DAC (:func:`~repro.core.rng.cdf_thresholds`;
+    a fresh track takes the plan's own root thresholds, as an untracked
+    network does), sweep with the thresholds as extra evidence columns, and
+    scatter :func:`carry_belief` back.  Indices past the table read the
+    published prior and write nothing (``mode="drop"``).
+    """
+    k = spec.card(spec.state_node)
+    prior = jnp.asarray(spec.cpt_rows(spec.state_node)[0], jnp.float32)
+    prior_thr = jnp.asarray(plan.nodes[plan.state][2][0], jnp.int32)
+    trans = [[float(p) for p in row] for row in spec.transition]
+    off = plan.slot_offsets[queries.index(spec.state_node)]
+
+    def step(key, ev_frames, table, idx, fresh, decide):
+        rows = table.at[idx].get(mode="clip")
+        # belief @ transition: one float32 product-sum per value, in row order
+        cols = []
+        for j in range(k):
+            acc = rows[:, 0] * jnp.float32(trans[0][j])
+            for i in range(1, k):
+                acc = acc + rows[:, i] * jnp.float32(trans[i][j])
+            cols.append(acc)
+        pred = jnp.stack(cols, axis=-1)
+        pred = jnp.where(fresh[:, None], prior[None, :], pred)
+        thr = jnp.where(fresh[:, None], prior_thr[None, :], rng.cdf_thresholds(pred))
+        out = net_sweep(key, jnp.concatenate([ev_frames, thr], axis=1), decide=decide,
+                        **sweep_kwargs)
+        numer, denom = out[0], out[1]
+        slots = numer[:, off : off + k - 1]
+        counts = jnp.concatenate(
+            [denom[:, None] - jnp.sum(slots, axis=-1, keepdims=True), slots], axis=-1
+        )
+        belief = cordiv.ratio_from_counts(counts, denom[:, None])
+        table = table.at[idx].set(carry_belief(belief, pred, denom), mode="drop")
+        return (assemble_counts(numer, denom),) + tuple(out[2:]) + (denom, table)
+
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def _run(key, ev_frames, table, idx, fresh):
+        return step(key, ev_frames, table, idx, fresh, False)
+
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def _decide(key, ev_frames, table, idx, fresh):
+        return step(key, ev_frames, table, idx, fresh, True)
+
+    return _run, _decide
 
 
 def _resolve_frame_mesh(devices) -> Tuple[Mesh | None, Tuple[str, ...]]:
@@ -661,6 +809,13 @@ def compile_network(
             "drift_epochs > 1 requires the fused lowering: the per-node "
             "unfused encoders sample one threshold snapshot per stream"
         )
+    if spec.state is not None:
+        if not fused:
+            raise ValueError(f"{spec.name}: a tracked network needs the fused lowering")
+        if spec.state_node not in queries:
+            raise ValueError(
+                f"{spec.name}: the state node {spec.state_node!r} must be a query"
+            )
     mask = bitops.pad_mask(n_bits)
 
     if fused:
@@ -706,6 +861,21 @@ def compile_network(
                 body, mesh=mesh, in_specs=(P(), bspec),
                 out_specs=(bspec,) * (3 if decide else 2), check_vma=False,
             )(rng.seed_words(key), ev_frames)
+
+        if spec.state is not None:
+            if n_shards > 1:
+                raise ValueError(
+                    f"{spec.name}: a tracked network runs on one device (its "
+                    "belief table is not sharded)"
+                )
+            _run, _decide = _tracked_programs(spec, queries, plan, assemble_counts,
+                                              sweep_kwargs)
+            return CompiledNetwork(
+                spec=spec, queries=queries, evidence=evidence, n_bits=n_bits,
+                share_entropy=share_entropy, estimator=estimator, fused=True,
+                query_cards=q_cards, _run=_run, _decide=_decide,
+                noise=noise, drift_epochs=drift_epochs, program=program,
+            )
 
         @jax.jit
         def _run(key, ev_frames):

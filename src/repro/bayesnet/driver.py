@@ -108,6 +108,22 @@ frame.  Every in-flight launch harvests against the plan it dispatched with
 dispatch), queued frames simply ride the next launch on the new plan, and
 the launch counter keeps advancing so entropy stays disjoint across the
 swap.  ``drift=None`` (default) costs nothing on the hot path.
+
+**Tracked networks.**  A driver of a tracked network (``net.tracked``) owns
+its belief table (``max_tracks`` rows on the device, shared by every
+bucket's program) and takes each frame with its track's row and a
+fresh-track flag (``submit(frames, tracks=, fresh=)``).  Every launch hands
+the table to the program and keeps the one it returns; launches on one
+device run in order, so no host round trip sits between them.  A launch
+holds at most one frame of a track: a second frame of a track already in the
+batch is held back and keeps its place at the head of the queue for the next
+launch.  With a tracer, ``pack`` holds a ``track.index`` span (building the
+launch's index and fresh arrays); the registry counts ``track.carried``,
+``track.fresh``, ``track.held`` (frames that accepted no sample and kept
+their prediction) and ``track.deferred`` (frames pushed to a later launch
+because their track was already in it).  Tracked drivers take no retry
+policy, and a frame re-dispatched after a failed launch is decided against
+its track's belief as the failed launch left it.
 """
 
 from __future__ import annotations
@@ -184,6 +200,7 @@ class FrameDriver:
         fault: LaunchFaultInjector | None = None,
         max_redispatch: int = 3,
         drift: DriftMonitor | None = None,
+        max_tracks: int | None = None,
     ):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
@@ -225,20 +242,51 @@ class FrameDriver:
         self.watch = (
             watchdog if watchdog is not None else StragglerWatch(metrics=metrics)
         )
+        # --- tracked state (inert for an untracked network) ---
+        self.tracks = None                       # the belief table, on the device
+        self.max_tracks = None
+        self._track_of: Dict[int, Tuple[int, bool]] = {}   # rid -> (row, fresh)
+        if net.tracked:
+            if max_tracks is None:
+                raise ValueError(f"{net.spec.name}: a tracked network needs max_tracks")
+            if retry is not None:
+                raise ValueError("a tracked network takes no retry policy")
+            self.max_tracks = int(max_tracks)
+            self.tracks = net.new_table(self.max_tracks)
+        elif max_tracks is not None:
+            raise ValueError(f"{net.spec.name}: an untracked network takes no max_tracks")
 
     # ------------------------------------------------------------- admission
-    def submit(self, frames) -> List[int]:
-        """Queue evidence frames ((n_ev,) each, or an (N, n_ev) array); returns rids."""
+    def submit(self, frames, tracks=None, fresh=None) -> List[int]:
+        """Queue evidence frames ((n_ev,) each, or an (N, n_ev) array); returns rids.
+
+        A tracked driver takes each frame's table row (``tracks``, in
+        ``[0, max_tracks)``) and whether it starts its track (``fresh``);
+        a track's frames are decided in the order they are submitted.
+        """
         frames = np.asarray(frames, np.int32)
         if frames.ndim == 1:
             frames = frames[None, :]
         assert frames.shape[1] == len(self.net.evidence), frames.shape
+        if self.tracks is not None:
+            if tracks is None or fresh is None:
+                raise ValueError("a tracked driver needs tracks and fresh for every frame")
+            tracks = np.asarray(tracks, np.int64).reshape(-1)
+            fresh = np.asarray(fresh, bool).reshape(-1)
+            if len(tracks) != len(frames) or len(fresh) != len(frames):
+                raise ValueError("tracks and fresh need one entry per frame")
+            if np.any((tracks < 0) | (tracks >= self.max_tracks)):
+                raise ValueError(f"track rows must lie in [0, {self.max_tracks})")
+        elif tracks is not None or fresh is not None:
+            raise ValueError("an untracked driver takes no tracks")
         rids = []
-        for row in frames:
+        for i, row in enumerate(frames):
             rid = self._next_rid
             self._next_rid += 1
             self._queue.append((rid, row))
             rids.append(rid)
+            if tracks is not None:
+                self._track_of[rid] = (int(tracks[i]), bool(fresh[i]))
         if self.metrics is not None:
             now = time.perf_counter()
             for rid in rids:
@@ -248,6 +296,12 @@ class FrameDriver:
         if self.trace is not None:
             self.trace.event("submit", n=len(rids))
         return rids
+
+    def reset_tracks(self) -> None:
+        """Forget every track: a new, empty belief table (queued frames keep theirs)."""
+        if self.tracks is None:
+            raise ValueError("an untracked driver has no tracks")
+        self.tracks = self.net.new_table(self.max_tracks)
 
     @property
     def pending(self) -> int:
@@ -301,6 +355,8 @@ class FrameDriver:
                 "swap_net query layout mismatch: "
                 f"{net.query_cards} != {self.net.query_cards}"
             )
+        if net.spec.state != self.net.spec.state:
+            raise ValueError("swap_net tracked state mismatch: the table would not fit")
         self.net = net
         self._nets = {0: net}
         if self.metrics is not None:
@@ -362,6 +418,34 @@ class FrameDriver:
             ev = np.concatenate([ev, pad], axis=0)
         return ev, n_real
 
+    def _pack_tracks(self, taken: list, bucket: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The launch's table rows and fresh flags; padding lanes read the
+        published prior and write nothing (a row past the table)."""
+        if self.trace is None:
+            idx, fresh = self._track_index(taken, bucket)
+        else:
+            with self.trace.span("track.index"):
+                idx, fresh = self._track_index(taken, bucket)
+        if self.metrics is not None:
+            n_fresh = int(fresh[: len(taken)].sum())
+            self.metrics.inc("track.fresh", n_fresh)
+            self.metrics.inc("track.carried", len(taken) - n_fresh)
+        return idx, fresh
+
+    def _track_index(self, taken: list, bucket: int) -> Tuple[np.ndarray, np.ndarray]:
+        idx = np.full(bucket, self.max_tracks, np.int32)
+        fresh = np.ones(bucket, bool)
+        for i, item in enumerate(taken):
+            idx[i], fresh[i] = self._track_of[item[0]]
+        return idx, fresh
+
+    def _run(self, net: CompiledNetwork, key: jax.Array, ev: np.ndarray, tracks):
+        """One program call; a tracked launch also swaps in the returned table."""
+        if tracks is None:
+            return net.run(key, ev)
+        post, accepted, self.tracks = net.run(key, ev, self.tracks, *tracks)
+        return post, accepted
+
     def _launch(self, key: jax.Array | None, taken: list, attempt: int) -> int:
         """Pack one batch at one attempt level, launch it, park the results."""
         tr, mx = self.trace, self.metrics
@@ -373,11 +457,16 @@ class FrameDriver:
             )
         if key is None:
             key = self._next_key()
+        tracks = None
         if tr is not None:
             with tr.span("pack", parent=lspan):
                 ev, n_real = self._pack(taken)
+                if self.tracks is not None:
+                    tracks = self._pack_tracks(taken, ev.shape[0])
         else:
             ev, n_real = self._pack(taken)
+            if self.tracks is not None:
+                tracks = self._pack_tracks(taken, ev.shape[0])
         self.last_launch_shape = ev.shape
         net = self.net if attempt == 0 else self._net_for(attempt)
         injected = (
@@ -400,9 +489,9 @@ class FrameDriver:
             # as soon as the work is enqueued, so this span is trace/compile
             # lookup + enqueue; the launch span stays open until harvest
             with tr.span("dispatch", parent=lspan, bucket=ev.shape[0]):
-                post, accepted = net.run(key, ev)
+                post, accepted = self._run(net, key, ev, tracks)
         else:
-            post, accepted = net.run(key, ev)
+            post, accepted = self._run(net, key, ev, tracks)
         ticket = self._dispatches
         self._dispatches += 1
         if self.watch.step_end(ticket):
@@ -431,6 +520,8 @@ class FrameDriver:
 
     def _dispatch(self, key: jax.Array | None) -> int:
         """Pack one main-queue batch (attempt 0), launch it (async)."""
+        if self.tracks is not None:
+            return self._launch(key, self._take_tracked(), 0)
         taken = [
             (rid, row, 0, 0)
             for rid, row in (
@@ -439,6 +530,28 @@ class FrameDriver:
             )
         ]
         return self._launch(key, taken, 0)
+
+    def _take_tracked(self) -> list:
+        """Up to ``max_batch`` queued frames, at most one per track.
+
+        A frame whose track is already in the batch is held back; the held
+        frames return to the head of the queue in their order, so each
+        track's frames launch in submission order.
+        """
+        taken, deferred, seen = [], [], set()
+        while self._queue and len(taken) < self.max_batch:
+            rid, row = self._queue.popleft()
+            track = self._track_of[rid][0]
+            if track in seen:
+                deferred.append((rid, row))
+                continue
+            seen.add(track)
+            taken.append((rid, row, 0, 0))
+        if deferred:
+            self._queue.extendleft(reversed(deferred))
+            if self.metrics is not None:
+                self.metrics.inc("track.deferred", len(deferred))
+        return taken
 
     def _dispatch_retries(self, key: jax.Array | None) -> int:
         """Launch one batch from the retry queue (head's attempt level)."""
@@ -518,6 +631,11 @@ class FrameDriver:
             for i, (rid, _, _, _) in enumerate(taken):
                 out[rid] = (post[i], int(accepted[i]))
                 emitted.append(rid)
+            if self.tracks is not None:
+                for rid in emitted:
+                    del self._track_of[rid]
+                if mx is not None:
+                    mx.inc("track.held", int(np.sum(accepted[:n_real] == 0)))
         else:
             base = self.net.n_bits
             clamped = bool(
@@ -625,6 +743,7 @@ class FrameDriver:
                 continue
             # redispatch budget exhausted: graceful degradation, never a drop
             self._fail_counts.pop(rid, None)
+            self._track_of.pop(rid, None)
             out[rid] = (self._zero_post(), 0)
             self.reports[rid] = FrameReport(
                 confidence=0.0, attempts=lf.attempt + 1, n_bits=0,

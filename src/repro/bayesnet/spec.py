@@ -27,6 +27,13 @@ Two CPT spellings:
 ``Node.categorical`` builds a nested-row node with ``k`` inferred from the row
 length.  Binary stays the ``k = 2`` special case with unchanged behaviour
 everywhere downstream.
+
+**Tracked state.**  A spec may name one root as the state a tracker carries
+from frame to frame: ``state = (root name, transition rows)``, row ``i``
+being ``P(state_t | state_{t-1} = i)``.  The root's own CPT row stays the
+published prior a fresh track starts from; a continuing track replaces it,
+frame by frame, with its last belief pushed through the transition
+(:mod:`repro.bayesnet.compile`).  Specs without a state are unchanged.
 """
 
 from __future__ import annotations
@@ -148,6 +155,7 @@ class NetworkSpec:
     nodes: Tuple[Node, ...]
     evidence: Tuple[str, ...] = ()
     queries: Tuple[str, ...] = ()
+    state: Tuple | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "nodes", tuple(self.nodes))
@@ -175,6 +183,8 @@ class NetworkSpec:
                     f"parent cardinalities "
                     f"{tuple(by_name[p].k for p in n.parents)}, got {got}"
                 )
+        if self.state is not None:
+            object.__setattr__(self, "state", _check_state(self, by_name))
         object.__setattr__(self, "_topo", _toposort(by_name))
 
     # ------------------------------------------------------------- accessors
@@ -221,6 +231,43 @@ class NetworkSpec:
     def cpt_rows(self, name: str) -> Tuple[Tuple[float, ...], ...]:
         """Canonical per-value CPT rows of ``name`` (mixed-radix row order)."""
         return self.node(name).value_probs()
+
+    @property
+    def state_node(self) -> str | None:
+        """The tracked root's name (``None`` for an untracked network)."""
+        return None if self.state is None else self.state[0]
+
+    @property
+    def transition(self) -> Tuple[Tuple[float, ...], ...] | None:
+        """The tracked root's ``k x k`` transition rows (``None`` untracked)."""
+        return None if self.state is None else self.state[1]
+
+
+def _check_state(spec: NetworkSpec, by_name: Dict[str, Node]) -> Tuple:
+    """Validated ``(root name, transition rows)`` of a tracked spec."""
+    try:
+        name, rows = spec.state
+    except (TypeError, ValueError):
+        raise ValueError(f"{spec.name}: state must be (root name, transition rows)")
+    node = by_name.get(name)
+    if node is None:
+        raise ValueError(f"{spec.name}: unknown state node {name!r}")
+    if node.parents:
+        raise ValueError(f"{spec.name}: state node {name!r} is not a root")
+    if name not in spec.queries:
+        raise ValueError(f"{spec.name}: state node {name!r} is not a query")
+    rows = tuple(tuple(float(p) for p in row) for row in rows)
+    if len(rows) != node.k or any(len(row) != node.k for row in rows):
+        raise ValueError(
+            f"{spec.name}: state {name!r} of cardinality {node.k} needs a "
+            f"{node.k} x {node.k} transition"
+        )
+    for row in rows:
+        if any(not 0.0 <= p <= 1.0 for p in row) or abs(sum(row) - 1.0) > _ROW_SUM_TOL:
+            raise ValueError(
+                f"{spec.name}: transition row {row} is not a distribution"
+            )
+    return (name, rows)
 
 
 def _toposort(by_name: Dict[str, Node]) -> Tuple[str, ...]:

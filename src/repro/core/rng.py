@@ -81,6 +81,26 @@ def cdf_thresholds_int(probs) -> tuple:
     return tuple(out)
 
 
+def cdf_thresholds(probs: jnp.ndarray) -> jnp.ndarray:
+    """:func:`cdf_thresholds_int` of traced rows: (..., k) float32 -> (..., k-1) int32.
+
+    The same 8-bit DAC: each tail sum ``P(value >= v)``, accumulated in
+    float32 from the top value down, is rounded half-to-even onto the
+    ``t / 256`` grid and clipped to [0, 256], then held non-increasing.
+    """
+    p = jnp.asarray(probs, jnp.float32)
+    k = p.shape[-1]
+    tails = [p[..., k - 1]]
+    for v in range(k - 2, 0, -1):
+        tails.append(tails[-1] + p[..., v])
+    out, prev = [], None
+    for tail in reversed(tails):
+        t = jnp.clip(jnp.round(tail * 256.0), 0.0, 256.0).astype(jnp.int32)
+        prev = t if prev is None else jnp.minimum(t, prev)
+        out.append(prev)
+    return jnp.stack(out, axis=-1)
+
+
 def n_rand_words(n_bits: int) -> int:
     """uint32 entropy words needed for ``n_bits`` stream bits (word-padded)."""
     return bitops.n_words(n_bits) * RAND_WORDS_PER_OUT_WORD

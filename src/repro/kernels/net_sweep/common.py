@@ -22,6 +22,13 @@ bit-planes.  Planes below the lowest set threshold bit of a node can never
 flip any comparison and are skipped entirely.  Binary nodes (``k=2``) collapse
 to exactly the single-chain lowering -- one threshold, one plane, bit-identical
 streams.
+
+A plan with a tracked ``state`` root takes that root's ``k-1`` thresholds per
+frame instead of from its CPT row: they ride in as ``k-1`` extra int32
+columns of the evidence tile, after the evidence columns, and feed the same
+borrow chain as per-frame mask words (every plane is generated, since no
+bit is known clear at trace time).  Plans without a state lower exactly as
+before.
 """
 
 from __future__ import annotations
@@ -117,6 +124,9 @@ class SweepPlan:
               row tuples with the same shape as that node's base ``rows``
               (same parents, same cardinality -- only the programmed
               thresholds drift).
+    state:    index of a root whose ``card - 1`` thresholds arrive per frame
+              as the evidence tile's last ``n_state_cols`` columns (every
+              epoch uses them); ``None`` for a plan without tracked state.
     """
 
     nodes: Tuple
@@ -124,6 +134,7 @@ class SweepPlan:
     queries: Tuple[int, ...]
     epochs: int = 1
     epoch_rows: Tuple = ()
+    state: int | None = None
 
     def __post_init__(self):
         object.__setattr__(
@@ -160,6 +171,10 @@ class SweepPlan:
                 raise ValueError(f"evidence/query node {n} out of range")
         if not self.queries:
             raise ValueError("SweepPlan needs at least one query node")
+        if self.state is not None:
+            object.__setattr__(self, "state", int(self.state))
+            if not 0 <= self.state < len(self.nodes) or self.nodes[self.state][0]:
+                raise ValueError(f"state node {self.state} is not a root of the plan")
         if self.epochs < 1:
             raise ValueError(f"epochs must be >= 1, got {self.epochs}")
         if len(self.epoch_rows) != self.epochs - 1:
@@ -184,6 +199,11 @@ class SweepPlan:
     def n_value_slots(self) -> int:
         """Numerator count columns: ``sum(card - 1)`` over the query nodes."""
         return sum(self.nodes[q][1] - 1 for q in self.queries)
+
+    @property
+    def n_state_cols(self) -> int:
+        """Per-frame threshold columns after the evidence (0 without a state)."""
+        return 0 if self.state is None else self.nodes[self.state][1] - 1
 
     @property
     def query_cards(self) -> Tuple[int, ...]:
@@ -313,6 +333,19 @@ def _level_masks(rows, level, gather, l):
     return masks, hi
 
 
+def _frame_level_masks(thresh):
+    """Per-plane mask words of per-frame thresholds: thresh (bf, 1) int32.
+
+    The tracked root's counterpart of :func:`_level_masks`: bit ``k`` of each
+    frame's threshold, spread over the frame's words, and the ``t = 256``
+    short-circuit.  No plane is known clear at trace time, so the borrow
+    chain generates all eight.
+    """
+    masks = [jnp.where(((thresh >> k) & 1) == 1, _FULL, jnp.uint32(0)) for k in range(8)]
+    hi = jnp.where(thresh >= 256, _FULL, jnp.uint32(0))
+    return masks, hi
+
+
 def _combine_epochs(per_epoch, emasks):
     """OR of per-epoch threshold-bit masks restricted to their word ranges.
 
@@ -396,7 +429,8 @@ def sweep_tile(
     """Counts for one tile: frames ``[f0, f0+bf)`` x words ``[w0, w0+bw)``.
 
     ev: (bf, >= n_ev) int32 evidence values for the tile's frames (one integer
-    in ``[0, card)`` per evidence node).  Returns ``(numer (bf, n_value_slots)
+    in ``[0, card)`` per evidence node), then, for a plan with a ``state``,
+    the state root's ``card - 1`` thresholds per frame.  Returns ``(numer (bf, n_value_slots)
     int32, denom (bf,) int32)`` -- popcounts of the acceptance stream and of
     each query value indicator ANDed with it, over this tile's words only
     (callers accumulate across word tiles).  Slot order: queries in plan
@@ -464,7 +498,10 @@ def sweep_tile(
 
         levels = []
         for v in range(card - 1):
-            if emasks is None:
+            if n == plan.state:
+                col = len(plan.evidence) + v
+                masks, hi = _frame_level_masks(ev[:, col : col + 1])
+            elif emasks is None:
                 masks, hi = _level_masks(rows, v, gather, l)
             else:
                 masks, hi = _epoch_level_masks(plan, n, v, gather, l, emasks)

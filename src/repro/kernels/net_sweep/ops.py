@@ -61,7 +61,9 @@ def net_sweep(
     """Run the fused sweep: per-frame independent joint samples, conditioned.
 
     ev_frames: (B, n_ev) int32 evidence values (one integer in ``[0, card)``
-    per evidence node), columns in ``plan.evidence`` order.  Returns
+    per evidence node), columns in ``plan.evidence`` order; a plan with a
+    tracked ``state`` takes ``plan.n_state_cols`` more columns, the state
+    root's per-frame DAC thresholds.  Returns
     ``(numer (B, n_value_slots) int32, denom (B,) int32)``: one CORDIV ratio
     numerator popcount per query *value* (queries in plan order, values
     ``1 .. card-1`` within a query; the value-0 count is ``denom`` minus the
@@ -84,8 +86,8 @@ def net_sweep(
     interpret = backend.resolve_interpret(interpret)
     use_kernel = backend.resolve_use_kernel(use_kernel, interpret)
     ev = jnp.asarray(ev_frames, jnp.int32)
-    assert ev.ndim == 2 and ev.shape[1] == len(plan.evidence), (
-        ev.shape, plan.evidence,
+    assert ev.ndim == 2 and ev.shape[1] == len(plan.evidence) + plan.n_state_cols, (
+        ev.shape, plan.evidence, plan.n_state_cols,
     )
     kd = rng.seed_words(key)
     if use_kernel:

@@ -18,6 +18,10 @@ CUSUM gauges (``<name>_drift_score_*``, ``<name>_drift_state``) plus alarm /
 reset counters, and the router adds ``router_recalibrations`` and the
 driver ``net_swaps`` / ``escalation_clamped`` counters, so a dashboard can
 watch a tenant walk HEALTHY -> DRIFTING -> RECALIBRATING and back.
+
+A tracked network's driver (DESIGN.md §16) adds a ``track.index`` span inside
+each launch's ``pack`` and the ``track.carried`` / ``track.fresh`` /
+``track.held`` / ``track.deferred`` counters.
 """
 
 from repro.obs.histogram import (  # noqa: F401

@@ -57,6 +57,15 @@ zero frames lost or reordered -- and resets the monitor.
 :meth:`BayesRouter.recalibrate` is the manual trigger,
 :meth:`BayesRouter.health` the state probe.
 
+**Tracked tenants.**  A tenant whose spec carries a tracked ``state``
+(:mod:`repro.bayesnet.spec`) is registered with ``max_tracks`` and submits
+each frame with its track id and a fresh-track flag
+(``submit(..., track_ids=, fresh=)``).  Its driver owns the belief table;
+the router hands a tenant's frames to it in submission order, serves them at
+the full stream length (a tracked tenant takes no degrade rung and no retry
+policy: each would need a table of its own), and passes the track ids
+through untouched.  Untracked tenants keep the calls they had.
+
 A degradation/retry interaction is also closed here: a DEGRADED tenant's
 :class:`~repro.bayesnet.reliability.RetryPolicy` escalation is clamped to
 the rung's n_bits (a degraded rung must not escalate its way back to full
@@ -190,15 +199,19 @@ class _Request:
     t_submit: float
     dispatch_seq: int = -1  # global dispatch order (admission-order probe)
     level: int = 0
+    track: int = -1         # tracked tenants: the frame's table row
+    fresh: bool = False     # tracked tenants: the frame starts its track
 
 
 class _Tenant:
     """One scenario's serving state: plans per degrade level + breaker."""
 
     def __init__(self, router: "BayesRouter", spec: NetworkSpec, name: str,
-                 salt: int, n_bits: int, noise: Optional[NoiseModel]):
+                 salt: int, n_bits: int, noise: Optional[NoiseModel],
+                 max_tracks: Optional[int] = None):
         self.router = router
         self.spec = spec
+        self.max_tracks = max_tracks
         self.name = name
         self.salt = salt
         self.n_bits = n_bits
@@ -231,6 +244,8 @@ class _Tenant:
         equals a shallower rung's collapses onto it, so "degraded" is never
         claimed without an actual fidelity cut.
         """
+        if self.tracked:
+            level = 0      # one table, at the full stream length
         while level > 0 and self.n_bits_for(level) == self.n_bits_for(level - 1):
             level -= 1
         d = self.drivers.get(level)
@@ -242,7 +257,7 @@ class _Tenant:
             net = compile_network(
                 self.spec, rung, noise=self.noise, trace=r.trace,
             )
-            retry = r.retry
+            retry = None if self.tracked else r.retry
             if level > 0 and retry is not None and retry.max_n_bits > rung:
                 # a DEGRADED rung must not escalate past its own fidelity
                 # cut: clamp the retry ladder to the rung's n_bits (frames
@@ -256,11 +271,15 @@ class _Tenant:
                 salt=self.salt + 7919 * level, retry=retry,
                 trace=r.trace, metrics=r.metrics, fault=r.fault,
                 max_redispatch=r.policy.max_redispatch,
-                drift=self.monitor,
+                drift=self.monitor, max_tracks=self.max_tracks,
             )
             self.drivers[level] = d
             self._fail_cursor[level] = 0
         return d, level
+
+    @property
+    def tracked(self) -> bool:
+        return self.spec.state is not None
 
     # ---------------------------------------------------------------- failure
     def earliest_dispatch(self, now: float) -> float:
@@ -423,12 +442,15 @@ class BayesRouter:
         salt: int | None = None,
         n_bits: int | None = None,
         noise: NoiseModel | None = None,
+        max_tracks: int | None = None,
     ) -> str:
         """Get-or-create a tenant; returns its name (LRU-touched).
 
         ``salt`` overrides the default CRC-of-name entropy salt (it persists
         across evictions either way).  ``n_bits`` / ``noise`` apply on first
-        registration only -- a cached tenant's plans are already built.
+        registration only -- a cached tenant's plans are already built.  A
+        tracked spec needs ``max_tracks``, the rows of its belief table (an
+        evicted tenant's table goes with it).
         """
         name = scenario if isinstance(scenario, str) else scenario.name
         t = self._tenants.get(name)
@@ -436,6 +458,10 @@ class BayesRouter:
             self._tenants.move_to_end(name)
             return name
         spec = by_name(scenario) if isinstance(scenario, str) else scenario
+        if (spec.state is not None) != (max_tracks is not None):
+            raise ValueError(
+                f"{name}: max_tracks is given exactly for a tracked network"
+            )
         if salt is not None:
             self._salts[name] = int(salt)
         else:
@@ -443,6 +469,7 @@ class BayesRouter:
         t = _Tenant(
             self, spec, name, self._salts[name],
             int(n_bits) if n_bits is not None else self.n_bits, noise,
+            None if max_tracks is None else int(max_tracks),
         )
         self._tenants[name] = t
         if self.metrics is not None:
@@ -476,6 +503,8 @@ class BayesRouter:
         scenario: Union[str, NetworkSpec],
         frames,
         deadline_ms: float | None = None,
+        track_ids=None,
+        fresh=None,
     ) -> List[int]:
         """Queue evidence frames for one tenant; returns rids.
 
@@ -484,12 +513,25 @@ class BayesRouter:
         scheduled inside their deadline -- expired on arrival, or the
         tenant's earliest dispatch plus its launch estimate lands past it --
         are shed immediately with ``REJECTED`` rather than silently queued.
+        A tracked tenant takes each frame's track id (its table row) and
+        whether the frame starts its track.
         """
         name = self.register(scenario)
         t = self._tenants[name]
         frames = np.asarray(frames, np.int32)
         if frames.ndim == 1:
             frames = frames[None, :]
+        if t.tracked:
+            if track_ids is None or fresh is None:
+                raise ValueError(f"{name}: a tracked tenant needs track_ids and fresh")
+            track_ids = np.asarray(track_ids, np.int64).reshape(-1)
+            fresh = np.asarray(fresh, bool).reshape(-1)
+            if len(track_ids) != len(frames) or len(fresh) != len(frames):
+                raise ValueError("track_ids and fresh need one entry per frame")
+            if np.any((track_ids < 0) | (track_ids >= t.max_tracks)):
+                raise ValueError(f"{name}: track ids must lie in [0, {t.max_tracks})")
+        elif track_ids is not None or fresh is not None:
+            raise ValueError(f"{name}: an untracked tenant takes no track ids")
         now = time.perf_counter()
         deadline = now + (
             deadline_ms / 1e3 if deadline_ms is not None
@@ -497,10 +539,12 @@ class BayesRouter:
         )
         rids = []
         est = t.launch_estimate()
-        for row in frames:
+        for i, row in enumerate(frames):
             rid = self._next_rid
             self._next_rid += 1
             req = _Request(rid, name, row, deadline, now)
+            if track_ids is not None:
+                req.track, req.fresh = int(track_ids[i]), bool(fresh[i])
             self.requests[rid] = req
             rids.append(rid)
             if t.earliest_dispatch(now) + est > deadline:
@@ -583,7 +627,14 @@ class BayesRouter:
             t = self._tenants[name]
             probe = t.breaker_open
             drv, eff = t.driver(level)
-            drv_rids = drv.submit(np.stack([r.row for r in reqs]))
+            if t.tracked:
+                # a track's frames reach its driver in submission order
+                reqs.sort(key=lambda r: r.rid)
+                drv_rids = drv.submit(np.stack([r.row for r in reqs]),
+                                      tracks=[r.track for r in reqs],
+                                      fresh=[r.fresh for r in reqs])
+            else:
+                drv_rids = drv.submit(np.stack([r.row for r in reqs]))
             for req, dr in zip(reqs, drv_rids):
                 t.rid_map[(eff, dr)] = req.rid
                 req.level = eff

@@ -106,3 +106,23 @@ def test_sharded_sweep_compiles_on_four_chips(topo, decide):
         )
     assert net.n_shards == 4
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("bucket", [1, 32])
+@pytest.mark.parametrize("name,root", [("obstacle-class", "obstacle"),
+                                       ("obstacle-detection", "obstacle"),
+                                       ("pedestrian-night", "pedestrian")])
+def test_tracked_sweep_compiles_served_buckets(one_chip, name, root, bucket):
+    """A tracked network's launch: table gather, prediction, per-frame thresholds, scatter."""
+    import dataclasses
+
+    base = by_name(name)
+    k = base.card(root)
+    trans = [[0.95 if i == j else 0.05 / (k - 1) for j in range(k)] for i in range(k)]
+    net = compile_network(dataclasses.replace(base, state=(root, trans)), n_bits=4096, **KERNEL)
+    args = (_sds((2,), jnp.uint32, one_chip),
+            _sds((bucket, len(base.evidence)), jnp.int32, one_chip),
+            _sds((96, k), jnp.float32, one_chip),
+            _sds((bucket,), jnp.int32, one_chip), _sds((bucket,), jnp.bool_, one_chip))
+    compiled = jax.jit(net.run).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
