@@ -299,8 +299,8 @@ class CompiledNetwork:
         default_factory=itertools.count, init=False, repr=False, compare=False
     )
 
-    def _check_frames(self, ev_frames, xp=jnp) -> jnp.ndarray:
-        ev = xp.asarray(ev_frames, xp.int32)
+    def _check_frames(self, ev_frames) -> jax.Array | np.ndarray:
+        ev = _array_module(ev_frames).asarray(ev_frames, np.int32)
         if ev.ndim != 2 or ev.shape[1] != len(self.evidence):
             raise ValueError(
                 f"evidence frames must be (B, {len(self.evidence)}), got {ev.shape}"
@@ -325,19 +325,22 @@ class CompiledNetwork:
         return jnp.zeros((int(max_tracks), self.spec.card(self.spec.state_node)), jnp.float32)
 
     def _args(self, ev_frames, table, track_idx, fresh) -> tuple:
-        """The jitted program's arguments after the key, checked."""
+        """The jitted program's arguments after the key, checked.
+
+        Host inputs (numpy arrays, lists) stay host arrays: the jitted call
+        moves them to the device in its own dispatch, where a device put of
+        each costs a transfer of its own.  Device arrays and tracers stay as
+        they are.
+        """
+        ev = self._check_frames(ev_frames)
         if not self.tracked:
             if table is not None or track_idx is not None or fresh is not None:
                 raise ValueError(f"{self.spec.name}: an untracked network takes no track state")
-            return (self._check_frames(ev_frames),)
+            return (ev,)
         if table is None or track_idx is None or fresh is None:
             raise ValueError(
                 f"{self.spec.name}: a tracked network needs table, track_idx and fresh"
             )
-        # host inputs stay host arrays: the jitted call moves them to the
-        # device in its own dispatch, where a device put of each costs a
-        # transfer of its own; device arrays and tracers stay as they are
-        ev = self._check_frames(ev_frames, _array_module(ev_frames))
         idx = _array_module(track_idx).asarray(track_idx, np.int32)
         new = _array_module(fresh).asarray(fresh, np.bool_)
         if idx.shape != (ev.shape[0],) or new.shape != (ev.shape[0],):
@@ -347,23 +350,27 @@ class CompiledNetwork:
             )
         return (ev, table, idx, new)
 
-    def _traced_call(
-        self, name: str, fn: Callable, key: jax.Array, ev_frames, tracks=(None,) * 3,
-        host_copies: int = 0,
-    ):
-        """One call of ``fn`` under a ``name`` span with two sync children.
+    def _call(self, name: str, fn: Callable, key: jax.Array, ev_frames, tracks,
+              host_copies: int):
+        """One call of the jitted ``fn``; starts its first ``host_copies`` outputs' copies.
 
-        ``<name>.evidence`` covers the host evidence -> device array
-        conversion, ``<name>.dispatch`` the jitted call (enqueue only, under
-        async dispatch; its ``compiled`` attr is true when the call grew the
-        jit's cache, which also adds 1 to the tracer's ``<name>.compiles``
-        count).  The first ``host_copies`` outputs' device -> host copies are
-        started after ``<name>.dispatch`` closes, inside the parent span, and
-        their number is added to the ``<name>.host_copies`` count.  The
-        parent's attrs are counts at the layer boundary taken from shapes,
-        never from a device sync.
+        Untraced, the call is :meth:`_args`, ``fn`` and the copy starts.  With
+        ``trace`` set it runs under a ``name`` span with two sync children:
+        ``<name>.evidence`` covers the checks of :meth:`_args` (host inputs
+        stay on the host), ``<name>.dispatch`` the jitted call, which moves
+        them to the device (enqueue only, under async dispatch; its
+        ``compiled`` attr is true when the call grew the jit's cache, which
+        also adds 1 to the tracer's ``<name>.compiles`` count).  The
+        device -> host copies are started after ``<name>.dispatch`` closes,
+        inside the parent span, and their number is added to the
+        ``<name>.host_copies`` count.  The parent's attrs are counts at the
+        layer boundary taken from shapes, never from a device sync.
         """
         tr = self.trace
+        if tr is None:
+            out = fn(key, *self._args(ev_frames, *tracks))
+            _start_host_copies(out[:host_copies])
+            return out
         with tr.span(name, network=self.spec.name, launch=next(self._calls)) as sp:
             with tr.span(f"{name}.evidence"):
                 args = self._args(ev_frames, *tracks)
@@ -375,8 +382,7 @@ class CompiledNetwork:
             dsp.attrs["compiled"] = compiled
             if compiled:
                 tr.inc(f"{name}.compiles")
-            if host_copies:
-                tr.inc(f"{name}.host_copies", _start_host_copies(out[:host_copies]))
+            tr.inc(f"{name}.host_copies", _start_host_copies(out[:host_copies]))
             sp.attrs.update(
                 frames=ev.shape[0], in_bytes=ev.nbytes + key.nbytes,
                 out_arrays=len(out), out_bytes=sum(x.nbytes for x in out),
@@ -386,21 +392,14 @@ class CompiledNetwork:
     def run(self, key: jax.Array, ev_frames, table=None, track_idx=None, fresh=None):
         """Posteriors and accepted counts of one launch: ``(post, accepted)``.
 
-        A tracked network's ``run`` returns its table last and, as ``decide``
-        does, starts the device -> host copies of ``post`` and ``accepted``
-        before it returns (``run.host_copies``, 2 a call, when traced): its
-        driver fetches both of every launch, and the table stays on the
-        device.  An untracked ``run`` starts no copies.
+        As :meth:`decide` does, ``run`` hands host inputs to the jitted call
+        as they are and starts the device -> host copies of ``post`` and
+        ``accepted`` before it returns (``run.host_copies``, 2 a call, when
+        traced): every caller fetches both.  A tracked network's ``run``
+        returns its table last, left on the device.
         """
-        tracks = (table, track_idx, fresh)
-        copies = 2 if self.tracked else 0
-        if self.trace is None:
-            out = self._run(key, *self._args(ev_frames, *tracks))
-            if copies:
-                _start_host_copies(out[:copies])
-            return out
-        return self._traced_call("run", self._run, key, ev_frames, tracks,
-                                 host_copies=copies)
+        return self._call("run", self._run, key, ev_frames, (table, track_idx, fresh),
+                          host_copies=2)
 
     def decide(self, key: jax.Array, ev_frames, table=None, track_idx=None, fresh=None):
         """Posteriors AND per-query MAP decisions in the same launch.
@@ -413,8 +412,11 @@ class CompiledNetwork:
         of ``run``'s posterior would, bit-for-bit.  Returns
         ``(post, decisions (B, n_q) int32, accepted)``.
 
-        The three outputs come back as ``jax.Array``s without waiting on the
-        device, with their device -> host copies already started: a caller's
+        Host evidence (numpy arrays, lists) goes to the jitted call as a
+        host array, which the call moves to the device in its own dispatch;
+        device arrays and tracers go as they are.  The three outputs come
+        back as ``jax.Array``s without waiting on the device, with their
+        device -> host copies already started: a caller's
         ``np.asarray`` finds each copy in flight or done instead of starting
         it, so the copies overlap one another and the caller's own work.
         Outputs of a call made under a JAX transformation are tracers and are
@@ -429,14 +431,8 @@ class CompiledNetwork:
         bit-identical.  A tracked network's table comes back last, left on
         the device.
         """
-        tracks = (table, track_idx, fresh)
-        if self.trace is None:
-            out = self._decide(key, *self._args(ev_frames, *tracks))
-            _start_host_copies(out[:3])
-            return out
-        return self._traced_call(
-            "decide", self._decide, key, ev_frames, tracks, host_copies=3
-        )
+        return self._call("decide", self._decide, key, ev_frames, (table, track_idx, fresh),
+                          host_copies=3)
 
 
 def sweep_plan(

@@ -4,8 +4,10 @@
 may change neither the values nor the calling convention: the outputs equal
 the jitted program's, called directly, bit for bit; they stay ``jax.Array``s
 (``decide`` does not wait on the device); reading one twice gives the same
-values.  The sharded program runs in a subprocess with forced host devices,
-as in ``test_sharded.py``, because jax pins the device count at first init.
+values.  Host evidence goes to the jitted call as a host array: it gives the
+outputs device evidence gives, bit for bit, in ``decide`` and ``run``.  The
+sharded program runs in a subprocess with forced host devices, as in
+``test_sharded.py``, because jax pins the device count at first init.
 """
 
 import os
@@ -13,6 +15,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -23,20 +26,27 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def check_decide_outputs(**compile_kw):
-    """decide == the jitted ``_decide`` on the same key and evidence."""
+    """decide == the jitted ``_decide`` on the same key and device evidence.
+
+    Host evidence (a numpy array, a list) and device evidence give the same
+    outputs, and ``run`` does as ``decide`` does.
+    """
     spec = by_name("intersection-cat")   # a 3-valued query: the (B, n_q, k) layout
     net = compile_network(spec, n_bits=512, **compile_kw)
     ev = np.asarray(sample_evidence(spec, jax.random.PRNGKey(1), 16))
     key = jax.random.PRNGKey(3)
-    out = net.decide(key, ev)
-    want = net._decide(key, net._check_frames(ev))
-    assert len(out) == len(want) == 3
-    for got, ref in zip(out, want):
-        assert isinstance(got, jax.Array) and not isinstance(got, np.ndarray)
-        first = np.asarray(got)
-        assert first.dtype == ref.dtype
-        np.testing.assert_array_equal(first, np.asarray(ref))
-        np.testing.assert_array_equal(np.asarray(got), first)
+    on_device = jnp.asarray(ev, jnp.int32)
+    for call, fn, n_out in ((net.decide, net._decide, 3), (net.run, net._run, 2)):
+        want = fn(key, on_device)
+        for evidence in (ev, ev.tolist(), on_device):
+            out = call(key, evidence)
+            assert len(out) == len(want) == n_out
+            for got, ref in zip(out, want):
+                assert isinstance(got, jax.Array) and not isinstance(got, np.ndarray)
+                first = np.asarray(got)
+                assert first.dtype == ref.dtype
+                np.testing.assert_array_equal(first, np.asarray(ref))
+                np.testing.assert_array_equal(np.asarray(got), first)
     return net
 
 

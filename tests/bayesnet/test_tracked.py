@@ -343,10 +343,11 @@ def test_an_empty_sample_keeps_the_prediction():
 
 
 @pytest.mark.parametrize("as_host", [True, False])
-def test_a_tracked_run_starts_its_two_host_copies_and_traces_the_same(as_host):
+@pytest.mark.parametrize("is_tracked", [True, False], ids=["tracked", "untracked"])
+def test_a_run_starts_its_two_host_copies_and_traces_the_same(is_tracked, as_host):
     from repro.obs import Tracer
 
-    spec = tracked("obstacle-class", "obstacle")
+    spec = tracked("obstacle-class", "obstacle") if is_tracked else by_name("obstacle-class")
     tr = Tracer()
     plain = compile_network(spec, n_bits=256)
     traced = compile_network(spec, n_bits=256, trace=tr)
@@ -355,14 +356,22 @@ def test_a_tracked_run_starts_its_two_host_copies_and_traces_the_same(as_host):
     if not as_host:
         ev, idx, fresh = jnp.asarray(ev), jnp.asarray(idx), jnp.asarray(fresh)
     key = jax.random.PRNGKey(2)
-    want = plain.run(key, ev[:3], plain.new_table(4), idx[:3], fresh[:3])
-    got = traced.run(key, ev[:3], traced.new_table(4), idx[:3], fresh[:3])
-    for a, b in zip(want, got):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert tr.count("run.host_copies") == 2
-    # the next launch carries the rows the first one wrote, traced or not
-    post, acc, _ = traced.run(key, ev[3:], got[2], idx[3:], fresh[3:])
-    wpost, wacc, _ = plain.run(key, ev[3:], want[2], idx[3:], fresh[3:])
-    np.testing.assert_array_equal(np.asarray(post), np.asarray(wpost))
-    np.testing.assert_array_equal(np.asarray(acc), np.asarray(wacc))
-    assert tr.count("run.host_copies") == 4 and tr.count("decide.host_copies") == 0
+    tables = (plain.new_table(4), traced.new_table(4)) if is_tracked else (None, None)
+    for calls, s in ((1, slice(0, 3)), (2, slice(3, None))):
+        tracks = [(t, idx[s], fresh[s]) if is_tracked else () for t in tables]
+        # the jitted program called directly on device inputs
+        ref = plain._run(key, jnp.asarray(ev[s], jnp.int32), *(
+            (jnp.array(tables[0]), jnp.asarray(idx[s], jnp.int32), jnp.asarray(fresh[s]))
+            if is_tracked else ()))
+        want = plain.run(key, ev[s], *tracks[0])
+        got = traced.run(key, ev[s], *tracks[1])
+        assert len(got) == len(ref) == (3 if is_tracked else 2)
+        for r, a, b in zip(ref, want, got):
+            assert isinstance(a, jax.Array) and isinstance(b, jax.Array)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+            np.testing.assert_array_equal(np.asarray(b), np.asarray(r))
+        assert tr.count("run.host_copies") == 2 * calls
+        # the next launch carries the rows this one wrote, traced or not
+        if is_tracked:
+            tables = (want[2], got[2])
+    assert tr.count("decide.host_copies") == 0

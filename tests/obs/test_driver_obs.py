@@ -267,9 +267,9 @@ class TestDecideTracing:
         for dec in (s for s in tr.spans if s.name == "decide"):
             kids = [s.name for s in tr.spans if s.parent_id == dec.span_id]
             assert kids == ["decide.evidence", "decide.dispatch"]
-        net.run(key, pn_ev)   # run starts no copies
+        net.run(key, pn_ev)   # run starts its own two, counted apart
         assert tr.count("decide.host_copies") == 9
-        assert "run.host_copies" not in tr.counters
+        assert tr.count("run.host_copies") == 2
 
     @pytest.mark.parametrize("fused", [True, False])
     def test_trace_none_records_nothing_and_is_bit_identical(self, pn_ev, fused):
